@@ -1,9 +1,5 @@
-"""Artifact freshness classification — dependency-free on purpose.
-
-The resume matrix's skip gate shells into this module per row; keeping
-it stdlib-only (no jax, no package imports) makes the gate instant and
-immune to backend-claim wedges. ``benchmarks.common`` re-uses the same
-predicate for ``load_partial`` so the two can't drift.
+"""Artifact freshness classification (stdlib only): ``benchmarks.common``
+uses it for ``load_partial`` to resume a killed multi-cell run.
 """
 
 from __future__ import annotations

@@ -62,7 +62,7 @@ def do_test(number):
 
 def main(sizes=(10, 100, 1000, 10_000, 20_000, 30_000)):
     # resume a killed run's cells, and checkpoint after every size: a
-    # watchdog kill on a tunnel-slow backend keeps the finished cells
+    # watchdog kill on a slow backend keeps the finished cells
     results = load_partial("full_bench")
     todo = [
         n for n in sizes

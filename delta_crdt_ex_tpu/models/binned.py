@@ -81,10 +81,9 @@ def pow2_tier(n: int, floor: int = 1) -> int:
 def pow4_tier(n: int, floor: int = 8) -> int:
     """Round up in ×4 steps — the WIRE tier. Sync slices vary per message
     (row count, alive count), and every distinct tier combination is a
-    fresh jit compile; on a remote-compile backend a compile can cost
-    minutes, so wire shapes trade up to 4× padding for ~half the tier
-    count (profiled: the 2-replica convergence bench spent 18.8s of 24.4s
-    in 15 tier compiles before this)."""
+    fresh jit compile, so wire shapes trade up to 4× padding for ~half
+    the tier count (profiled: the 2-replica convergence bench spent 18.8s
+    of 24.4s in 15 tier compiles before this)."""
     c = floor
     while c < n:
         c *= 4

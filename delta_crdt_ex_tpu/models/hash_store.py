@@ -335,25 +335,12 @@ def winner_rows(state: HashStore, rows):
     return jit.winner_rows_packed(state, rows, lanes=_dense_lanes(counts))
 
 
-#: ``(impl | None, tag)`` from ``probed_lookup_fn`` — resolved on the
-#: first point read so a Mosaic lowering failure surfaces there with
-#: its reason, then cached for the process (the ``ops/pallas_tree.py``
-#: selection pattern)
-_PROBED: "tuple | None" = None
-
-
 def winners_for_keys(state: HashStore, khash):
-    """LWW point lookup: the HBM-resident Pallas probe kernel where it
-    lowers (TPU) and the table fits its two-row cover, the jitted jnp
-    reference everywhere else (CPU tier-1 runs the jnp path by
-    construction — the two agree bit-for-bit, pinned in
-    ``tests/test_hash_store.py``)."""
-    global _PROBED
-    if _PROBED is None:
-        _PROBED = _ops().probed_lookup_fn()
-    fn, _tag = _PROBED
-    if fn is not None and state.probe_window <= 128 and state.table_size >= 256:
-        return fn(state, khash)
+    """LWW point lookup: the HBM-resident Pallas probe kernel on TPU, the
+    jitted jnp reference on every other platform (the two agree bit for
+    bit on found keys, pinned in ``tests/test_hash_store.py``)."""
+    if jax.default_backend() == "tpu":
+        return jit.probe_winners(state, khash)
     return jit.winners_for_keys(state, khash)
 
 

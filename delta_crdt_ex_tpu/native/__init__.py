@@ -13,6 +13,7 @@ Current components:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,24 +22,33 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "fasthash.cpp")
-_SO = os.path.join(_DIR, "libfasthash.so")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    """The library built from this exact source: keyed on the source's
+    hash, so a library left from other sources (or copied in with the
+    tree) is never loaded."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libfasthash-{digest}.so")
+
+
+def _build(so: str) -> bool:
+    tmp = f"{so}.{os.getpid()}.tmp"  # concurrent builders never share it
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", _SO + ".tmp"],
+            ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", tmp],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        os.replace(_SO + ".tmp", _SO)
+        os.replace(tmp, so)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         return False
 
 
@@ -48,11 +58,11 @@ def _load():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-            if not _build():
-                return None
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
+            return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
             lib.hash64_batch.argtypes = [
                 ctypes.c_void_p,
                 ctypes.c_void_p,

@@ -747,91 +747,119 @@ def compact_rows(state: HashStore) -> HashStore:
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU point-lookup kernel (probe-selected, jnp path is reference)
+# Pallas TPU point-lookup kernel (the jnp path above is its reference)
 
-#: lanes per VMEM row the kernel DMAs; probe windows must fit two rows
+#: lanes per table row the kernel DMAs
 _PL_LANES = 128
+#: fewest queries per grid step: a rank-1 SMEM block spans a multiple
+#: of 128 entries
+_PL_QUERIES = 128
+#: table columns the kernel DMAs per query
+_PL_COLS = 8
+
+
+def _cover_rows(window: int) -> int:
+    """Table rows that always hold a whole probe window: a window base
+    is group-aligned, so it starts at most ``_PL_LANES - GROUP`` lanes
+    into its row."""
+    return (_PL_LANES - GROUP + window - 1) // _PL_LANES + 1
 
 
 def _probe_kernel_body(nq: int, window: int, r: int, nrows: int):
     """Build the kernel fn for a static (block, window, R) shape. All
-    comparisons run on int32/uint32 bit-halves (TPU has no native i64);
-    the LWW order is resolved by lexicographic candidate narrowing over
-    (ts_hi, ts_lo, gid_hi, gid_lo, ctr) — the ``_argmax_lww`` scheme on
-    word halves. Per query the kernel DMAs exactly the two 128-lane HBM
-    rows covering its probe window — the WarpSpeed access shape."""
+    comparisons run on int32 bit-halves (TPU has no native i64): an
+    unsigned word is ordered as a signed one after flipping its sign
+    bit, and the LWW order is resolved by lexicographic candidate
+    narrowing over (ts_hi, ts_lo, gid_hi, gid_lo, ctr) — the
+    ``_argmax_lww`` scheme on word halves. Per query the kernel DMAs
+    only the 128-lane HBM rows covering its probe window — the
+    WarpSpeed access shape."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     def kernel(
         base_ref,  # SMEM int32[NQ]  window base lane per query
-        qk_ref,  # VMEM int32[NQ, 2] query key halves (lo, hi)
+        qlo_ref, qhi_ref,  # SMEM int32[NQ] query key halves
+        gid_ref,  # SMEM int32[2R] ctx_gid halves (hi[0..R), lo[0..R))
         tkey_lo_ref, tkey_hi_ref,  # HBM int32[H/128, 128] key bit-halves
         alive_ref,  # HBM int32[H/128, 128]
         node_ref,  # HBM int32[H/128, 128]
         ctr_ref,  # HBM int32[H/128, 128] (uint32 bits)
         tslo_ref, tshi_ref,  # HBM int32[H/128, 128] ts bit-halves
         valh_ref,  # HBM int32[H/128, 128]
-        gid_ref,  # VMEM int32[2, R] ctx_gid bit-halves
-        out_ref,  # VMEM int32[NQ, 8]
-        scratch, sem,
+        out_ref,  # SMEM int32[NQ * 8]
+        scratch, sems,
     ):
-        def umax(x, m):  # max of uint32-bits x over mask m (0 neutral)
-            return jnp.max(jnp.where(m, pltpu.bitcast(x, jnp.uint32), jnp.uint32(0)))
+        cols = (tkey_lo_ref, tkey_hi_ref, alive_ref, node_ref, ctr_ref,
+                tslo_ref, tshi_ref, valh_ref)
+        # x64 is on process-wide: every scalar the kernel computes with
+        # is an explicit int32, or Mosaic is handed i64 operands
+        i32 = jnp.int32
+        low = i32(-(2**31))  # also the sign bit
+        far = i32(2**30)
+        lanes = i32(_PL_LANES)
+        cover = _cover_rows(window)
+        tile = (cover, _PL_LANES)
+        lane = (
+            jax.lax.broadcasted_iota(jnp.int32, tile, 0) * lanes
+            + jax.lax.broadcasted_iota(jnp.int32, tile, 1)
+        )
 
-        for q in range(nq):
+        def one_query(q, carry):
             b = base_ref[q]
-            # two 128-lane rows always cover [b, b+window) for window ≤
-            # 128 — except when b sits in the LAST row, where the pair
-            # shifts back one row (the tail lanes past b+window are
-            # masked off by the window test either way)
-            row0 = jnp.minimum(b // _PL_LANES, nrows - 2)
-            for ci, ref in enumerate(
-                (tkey_lo_ref, tkey_hi_ref, alive_ref, node_ref, ctr_ref,
-                 tslo_ref, tshi_ref, valh_ref)
-            ):
-                dma = pltpu.make_async_copy(
-                    ref.at[pl.ds(row0, 2)], scratch.at[ci], sem
+            # ``cover`` rows from b's row hold [b, b+window); near the
+            # table's end the rows shift back (lanes outside the window
+            # are masked off by the window test either way)
+            row0 = jnp.minimum(jax.lax.div(b, lanes), i32(nrows - cover))
+            copies = [
+                pltpu.make_async_copy(
+                    ref.at[pl.ds(row0, cover)],
+                    scratch.at[i32(ci)],
+                    sems.at[i32(ci)],
                 )
-                dma.start()
-                dma.wait()
-            lane = (
-                jax.lax.broadcasted_iota(jnp.int32, (2, _PL_LANES), 0) * _PL_LANES
-                + jax.lax.broadcasted_iota(jnp.int32, (2, _PL_LANES), 1)
-            )
-            off = (lane + row0 * _PL_LANES) - b
-            in_win = (off >= 0) & (off < window)
-            keq = (scratch[0] == qk_ref[q, 0]) & (scratch[1] == qk_ref[q, 1])
-            alive = scratch[2] != 0
+                for ci, ref in enumerate(cols)
+            ]
+            for c in copies:
+                c.start()
+            for c in copies:
+                c.wait()
+            flat = lane + row0 * lanes
+            off = flat - b
+            in_win = (off >= i32(0)) & (off < i32(window))
+            keq = (scratch[i32(0)] == qlo_ref[q]) & (scratch[i32(1)] == qhi_ref[q])
+            alive = scratch[i32(2)] != i32(0)
             m = in_win & keq & alive
-            # gid bit-halves via unrolled select chain (R is static)
-            node = scratch[3]
-            g_lo = jnp.zeros((2, _PL_LANES), jnp.int32)
-            g_hi = jnp.zeros((2, _PL_LANES), jnp.int32)
+            node = scratch[i32(3)]
+            g_lo = jnp.zeros(tile, jnp.int32)
+            g_hi = jnp.zeros(tile, jnp.int32)
             for i in range(r):
-                g_lo = jnp.where(node == i, gid_ref[1, i], g_lo)
-                g_hi = jnp.where(node == i, gid_ref[0, i], g_hi)
-            # lexicographic narrowing: ts is int64 but non-negative here
-            # (LWW clock), so unsigned half-compares order it correctly
+                g_hi = jnp.where(node == i32(i), gid_ref[i32(i)], g_hi)
+                g_lo = jnp.where(node == i32(i), gid_ref[i32(r + i)], g_lo)
             cand = m
-            for part in (scratch[6], scratch[5], g_hi, g_lo, scratch[4]):
-                u = pltpu.bitcast(part, jnp.uint32)
-                cand = cand & (u == umax(part, cand))
-            found = jnp.any(m)
-            # winner lane: the (unique) surviving candidate's flat index
-            pick = lambda col: jnp.max(jnp.where(cand, col, jnp.int32(-(2**30))))
-            free = in_win & (scratch[2] == 0)  # dead lanes are free
-            free_slot = jnp.min(
-                jnp.where(free, lane + row0 * _PL_LANES, jnp.int32(2**30))
+            for part in (scratch[i32(6)], scratch[i32(5)], g_hi, g_lo, scratch[i32(4)]):
+                u = part ^ low  # unsigned order as signed
+                top = jnp.max(jnp.where(cand, u, low))
+                cand = cand & (u == top)
+            found = jnp.max(m.astype(jnp.int32))
+            # winner lane: the (unique) surviving candidate's value
+            pick = lambda col: jnp.max(jnp.where(cand, col, low))
+            free_slot = jnp.min(jnp.where(in_win & ~alive, flat, far))
+            hit = found != i32(0)
+            vals = (
+                found,
+                jnp.where(hit, pick(flat), i32(-1)),
+                jnp.where(hit, pick(node), i32(0)),
+                jnp.where(hit, pick(scratch[i32(4)]), i32(0)),
+                jnp.where(hit, pick(scratch[i32(7)]), i32(0)),
+                jnp.where(hit, pick(scratch[i32(5)]), i32(0)),
+                jnp.where(hit, pick(scratch[i32(6)]), i32(0)),
+                free_slot,
             )
-            out_ref[q, 0] = found.astype(jnp.int32)
-            out_ref[q, 1] = jnp.where(found, pick(lane) + row0 * _PL_LANES, -1)
-            out_ref[q, 2] = jnp.where(found, pick(node), 0)
-            out_ref[q, 3] = jnp.where(found, pick(scratch[4]), 0)
-            out_ref[q, 4] = jnp.where(found, pick(scratch[7]), 0)
-            out_ref[q, 5] = jnp.where(found, pick(scratch[5]), 0)
-            out_ref[q, 6] = jnp.where(found, pick(scratch[6]), 0)
-            out_ref[q, 7] = free_slot
+            for c, v in enumerate(vals):
+                out_ref[q * i32(_PL_COLS) + i32(c)] = v
+            return carry
+
+        jax.lax.fori_loop(i32(0), i32(nq), one_query, i32(0))
 
     return kernel
 
@@ -839,78 +867,72 @@ def _probe_kernel_body(nq: int, window: int, r: int, nrows: int):
 def probe_lookup_pallas(
     khash: jnp.ndarray, state: HashStore, interpret: bool = False
 ) -> jnp.ndarray:
-    """Pallas point lookup: per query key, DMA the two 128-lane HBM rows
+    """Pallas point lookup: per query key, DMA the 128-lane HBM rows
     covering its probe window into VMEM and resolve the LWW winner (and
     the first free lane — the upsert probe) on-chip. Returns
     ``int32[Q, 8]`` (found, slot, node, ctr, valh, ts_lo, ts_hi,
     free_slot). 64-bit columns travel as uint32 half-lanes (TPU has no
-    native i64)."""
+    native i64); timestamps are non-negative LWW stamps. A table smaller
+    than the rows one window needs is padded with dead lanes."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     H = state.table_size
     W = state.probe_window
     R = state.replica_capacity
-    if W > _PL_LANES:
-        raise ValueError(f"probe window {W} exceeds the kernel's two-row cover")
-    if H < 2 * _PL_LANES:
-        raise ValueError(
-            f"table of {H} lanes is below the kernel's two-row minimum; "
-            "use the jnp reference path for tiny tables"
-        )
+    cover = _cover_rows(W)
+    nrows = max(-(-H // _PL_LANES), cover)
     q = khash.shape[0]
-    nq = 8
+    # XLA tiles a rank-1 SMEM array by min(size, 1024) lanes, and each
+    # block must match that tiling: pow2 up to one block, then whole
+    # blocks of 1024 queries
+    nq = min(max(pl.next_power_of_2(q), _PL_QUERIES), 1024)
     q_pad = -(-q // nq) * nq
     kh = jnp.concatenate([khash, jnp.zeros(q_pad - q, jnp.uint64)])
-    base = probe_base(kh, H)
-    shape2d = (H // _PL_LANES, _PL_LANES)
+    rows = lambda a: jnp.pad(a, (0, nrows * _PL_LANES - H)).reshape(
+        nrows, _PL_LANES
+    )
     i32 = lambda u32_bits: jax.lax.bitcast_convert_type(u32_bits, jnp.int32)
     lo32 = lambda a64: i32((a64 & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32))
     hi32 = lambda a64: i32((a64 >> jnp.uint64(32)).astype(jnp.uint32))
     ts_u = state.ts.astype(jnp.uint64)  # non-negative LWW stamps
-    qk = jnp.stack([lo32(kh), hi32(kh)], axis=1)
-    gid2 = jnp.stack([hi32(state.ctx_gid), lo32(state.ctx_gid)])
+    smem = lambda n: pl.BlockSpec((n,), lambda i: (i,), memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
-        _probe_kernel_body(nq, W, R, H // _PL_LANES),
-        out_shape=jax.ShapeDtypeStruct((q_pad, 8), jnp.int32),
+        _probe_kernel_body(nq, W, R, nrows),
+        out_shape=jax.ShapeDtypeStruct((q_pad * _PL_COLS,), jnp.int32),
         grid=(q_pad // nq,),
         in_specs=[
-            pl.BlockSpec((nq,), lambda i: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((nq, 2), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((2, R), lambda i: (0, 0)),
+            smem(nq), smem(nq), smem(nq),
+            pl.BlockSpec(
+                (2 * R,), lambda i: (jnp.int32(0),), memory_space=pltpu.SMEM
+            ),
+            *[hbm] * _PL_COLS,
         ],
-        out_specs=pl.BlockSpec((nq, 8), lambda i: (i, 0)),
+        out_specs=smem(nq * _PL_COLS),
         scratch_shapes=[
-            pltpu.VMEM((8, 2, _PL_LANES), jnp.int32),
-            pltpu.SemaphoreType.DMA(()),
+            pltpu.VMEM((_PL_COLS, cover, _PL_LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((_PL_COLS,)),
         ],
         interpret=interpret,
     )(
-        base, qk,
-        lo32(state.key).reshape(shape2d), hi32(state.key).reshape(shape2d),
-        state.alive.astype(jnp.int32).reshape(shape2d),
-        state.node.reshape(shape2d),
-        i32(state.ctr).reshape(shape2d),
-        lo32(ts_u).reshape(shape2d), hi32(ts_u).reshape(shape2d),
-        i32(state.valh).reshape(shape2d),
-        gid2,
+        probe_base(kh, H), lo32(kh), hi32(kh),
+        jnp.concatenate([hi32(state.ctx_gid), lo32(state.ctx_gid)]),
+        rows(lo32(state.key)), rows(hi32(state.key)),
+        rows(state.alive.astype(jnp.int32)),
+        rows(state.node),
+        rows(i32(state.ctr)),
+        rows(lo32(ts_u)), rows(hi32(ts_u)),
+        rows(i32(state.valh)),
     )
-    return out[:q]
+    return out.reshape(q_pad, _PL_COLS)[:q]
 
 
 def probe_winners(state: HashStore, khash: jnp.ndarray, interpret: bool = False):
     """:class:`~delta_crdt_ex_tpu.ops.binned.KeyWinners` view of the
-    Pallas probe kernel's raw int32 grid — the drop-in accelerated form
-    of :func:`winners_for_keys` (the model selects it per read when the
-    probe succeeded and the table fits the kernel's two-row cover)."""
+    Pallas probe kernel's raw int32 grid — the TPU form of
+    :func:`winners_for_keys` (found keys agree bit for bit; a key that
+    is not found reports zeros)."""
     out = probe_lookup_pallas(khash, state, interpret=interpret)
     u32 = lambda col: jax.lax.bitcast_convert_type(col, jnp.uint32)
     node = jnp.clip(out[:, 2], 0, state.replica_capacity - 1)
@@ -923,31 +945,3 @@ def probe_winners(state: HashStore, khash: jnp.ndarray, interpret: bool = False)
         valh=u32(out[:, 4]),
         ts=((ts_hi << jnp.uint64(32)) | ts_lo).astype(jnp.int64),
     )
-
-
-def probed_lookup_fn():
-    """Probe Pallas availability once (the ``ops/pallas_tree.py``
-    selection pattern) and return ``(winners_for_keys_impl, tag)``: the
-    HBM-resident probe kernel where Mosaic lowers it, the pure-jnp
-    reference everywhere else (CPU tier-1 runs the jnp path by
-    construction). The probe executes a tiny lookup so a lowering
-    failure surfaces HERE with its reason, not in a read path."""
-    tag = "xla"
-    try:
-        st = HashStore.new(num_buckets=4, bin_capacity=64, replica_capacity=8)
-        # crdtlint: allow[host-sync] probe must synchronise by design
-        jax.block_until_ready(
-            probe_winners(st, jnp.zeros(2, jnp.uint64))
-        )
-        return probe_winners, "pallas"
-    except Exception as e:  # surface WHY (the pallas_tree round-4 lesson)
-        import sys
-
-        msg = " ".join(str(e).split())
-        print(
-            f"[hash_map] probe_lookup_pallas probe failed: {msg[:300]}",
-            file=sys.stderr,
-            flush=True,
-        )
-        tag = f"xla (pallas probe failed: {type(e).__name__}: {msg[:120]})"
-    return None, tag

@@ -29,17 +29,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6 ships shard_map under experimental,
-    # with the replication check spelled check_rep instead of check_vma
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_compat(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-        )
-
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from delta_crdt_ex_tpu.models.binned import BinnedStore

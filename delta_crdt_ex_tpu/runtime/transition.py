@@ -45,29 +45,20 @@ import jax
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _new_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _new_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-except ImportError:  # jax < 0.6 ships shard_map under experimental,
-    # with the replication check spelled check_rep instead of check_vma
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _old_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-
 from delta_crdt_ex_tpu.models.binned import BinnedStore
 from delta_crdt_ex_tpu.models.hash_store import HashStore
 from delta_crdt_ex_tpu.ops import binned as binned_ops
 from delta_crdt_ex_tpu.ops import hash_map as hash_ops
 from delta_crdt_ex_tpu.utils.jitcache import named_jit
+
+
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication check off: each lifted
+    kernel runs its unchanged per-shard vmap form."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
+
 
 # ---------------------------------------------------------------------------
 # single-replica transitions (the replica loop's device calls, re-exported

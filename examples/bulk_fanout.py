@@ -14,8 +14,7 @@ value hashes, like `bench.py`); the replica runtime (`start_link`)
 wraps the same kernels for arbitrary Python keys and values.
 
 Run: python examples/bulk_fanout.py
-(runs on the configured accelerator when its pool is reachable, else
-falls back to a labelled CPU run; JAX_PLATFORMS=cpu forces CPU)
+(runs on the default JAX backend; JAX_PLATFORMS=cpu runs it on the CPU)
 """
 
 import dataclasses
@@ -24,9 +23,7 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from examples._util import ensure_backend
 
-ensure_backend()
 
 import jax.numpy as jnp
 import numpy as np

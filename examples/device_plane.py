@@ -8,21 +8,15 @@ devices; the same program on a TPU pod keeps slice bytes off the host
 entirely.
 
 Run: python examples/device_plane.py
-(defaults to 4 virtual CPU devices; a pre-forced environment —
-JAX_PLATFORMS/XLA_FLAGS already set — keeps its own devices)
+(one replica per device JAX finds; on a CPU host,
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4
+gives it four virtual devices)
 """
 
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from delta_crdt_ex_tpu.utils.devices import backend_initialised
-
-if not backend_initialised(default=False):  # allow pre-forced environments
-    from delta_crdt_ex_tpu.utils.devices import force_cpu_devices
-
-    force_cpu_devices(4)  # CPU demo default; real hardware uses its own devices
 
 import jax
 

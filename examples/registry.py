@@ -14,8 +14,7 @@ a node dies. This demo builds exactly that on the TPU-native runtime:
   removes the dead node's registrations — the Horde cleanup pattern.
 
 Run: python examples/registry.py
-(runs on the configured accelerator when its pool is reachable, else
-falls back to a labelled CPU run; JAX_PLATFORMS=cpu forces CPU)
+(runs on the default JAX backend; JAX_PLATFORMS=cpu runs it on the CPU)
 """
 
 import os
@@ -23,9 +22,8 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from examples._util import ensure_backend, wait_until
+from examples._util import wait_until
 
-ensure_backend()
 
 import delta_crdt_ex_tpu as dc
 

@@ -7,9 +7,9 @@ neighbour, requests only the differing buckets, and joins the returned
 slice shard-locally. N-1 steps converge an N-device ring.
 
 Run: python examples/spmd_gossip.py
-(defaults to 8 virtual CPU devices; a pre-forced environment —
-JAX_PLATFORMS/XLA_FLAGS already set — keeps its own devices, so the
-same file runs unchanged on a real multi-chip mesh)
+(one replica per device JAX finds; on a CPU host,
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
+gives it eight virtual devices)
 """
 
 import os
@@ -18,13 +18,6 @@ import sys
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from delta_crdt_ex_tpu.utils.devices import backend_initialised
-
-if not backend_initialised(default=False):  # allow pre-forced environments
-    from delta_crdt_ex_tpu.utils.devices import force_cpu_devices
-
-    force_cpu_devices(8)
 
 import jax
 import jax.numpy as jnp

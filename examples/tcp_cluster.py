@@ -4,8 +4,7 @@ feed — the capabilities a reference user reaches for in production:
 `storage_module` crash recovery.
 
 Run: python examples/tcp_cluster.py
-(runs on the configured accelerator when its pool is reachable, else
-falls back to a labelled CPU run; JAX_PLATFORMS=cpu forces CPU)
+(runs on the default JAX backend; JAX_PLATFORMS=cpu runs it on the CPU)
 """
 
 import os
@@ -13,9 +12,8 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from examples._util import ensure_backend, wait_until
+from examples._util import wait_until
 
-ensure_backend()
 
 import delta_crdt_ex_tpu as dc
 from delta_crdt_ex_tpu.runtime.storage import FileStorage

@@ -836,36 +836,64 @@ def test_catchup_stats_record_chunk_fill(tmp_path):
 # Pallas point-lookup kernel (interpret mode = CPU-checkable)
 
 
-def test_pallas_probe_lookup_interpret_matches_reference():
+def _probe_parity(window: int) -> None:
     m = HashKernelMap(gid=21, capacity=256, num_buckets=16)
+    m.state = dataclasses.replace(m.state, probe_window=window)
     for i in range(30):
         m.add(i, i * 3 + 1, ts=i + 1)
     for i in range(0, 30, 3):
         m.remove(i, ts=100 + i)
-    keys = np.arange(0, 34, dtype=np.uint64)
-    try:
-        out = np.asarray(
-            hash_ops.probe_lookup_pallas(jnp.asarray(keys), m.state, interpret=True)
-        )
-    except Exception as e:  # pallas interpret API churn: only the
-        pytest.skip(f"pallas interpret unavailable: {e!r}")  # TPU path may skip
-    ref = m.M.winners_for_keys(m.state, jnp.asarray(keys))
+    keys = jnp.asarray(np.arange(0, 34, dtype=np.uint64))
+    out = np.asarray(hash_ops.probe_lookup_pallas(keys, m.state, interpret=True))
+    ref = hash_ops.winners_for_keys(m.state, keys)
     found_ref = np.asarray(ref.found)
+    assert found_ref.any() and not found_ref.all()
     assert np.array_equal(out[:, 0].astype(bool), found_ref)
     # winner columns agree wherever found (ctr + valh identify the dot)
     sel = found_ref
     assert np.array_equal(out[sel, 3].astype(np.uint32), np.asarray(ref.ctr)[sel])
     assert np.array_equal(out[sel, 4].astype(np.uint32), np.asarray(ref.valh)[sel])
+    # the KeyWinners view agrees bit for bit on every found key
+    got = hash_ops.probe_winners(m.state, keys, interpret=True)
+    for col in ("gid", "ctr", "valh", "ts"):
+        assert np.array_equal(
+            np.asarray(getattr(got, col))[sel], np.asarray(getattr(ref, col))[sel]
+        ), col
     # free-slot probe: a returned lane really is free (dead) and in-window
     free = out[:, 7]
     in_table = free < m.state.table_size
     assert (~np.asarray(m.state.alive)[free[in_table]]).all()
 
 
-def test_probed_lookup_fn_reports_selection():
-    fn, tag = hash_ops.probed_lookup_fn()
-    # CPU tier-1: the probe must fall back (and say why) or succeed
-    assert (fn is None and tag.startswith("xla")) or tag == "pallas"
+def test_pallas_probe_lookup_interpret_matches_reference():
+    _probe_parity(32)
+
+
+def test_pallas_probe_lookup_wide_window_matches_reference():
+    """A window past one 128-lane row makes the kernel DMA three rows."""
+    _probe_parity(256)
+
+
+def test_probed_lookup_fn_reports_selection(monkeypatch):
+    """Point reads pick their kernel by platform: the jnp reference off
+    TPU, the Pallas probe kernel on TPU — never a probe-and-fallback."""
+    import jax
+
+    from delta_crdt_ex_tpu.models import hash_store
+
+    calls = []
+    monkeypatch.setattr(
+        hash_store.jit, "winners_for_keys", lambda st, k: calls.append("jnp")
+    )
+    monkeypatch.setattr(
+        hash_store.jit, "probe_winners", lambda st, k: calls.append("pallas")
+    )
+    st = HashStore.new(num_buckets=4, bin_capacity=64, replica_capacity=8)
+    keys = jnp.zeros(2, jnp.uint64)
+    hash_store.winners_for_keys(st, keys)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hash_store.winners_for_keys(st, keys)
+    assert calls == ["jnp", "pallas"]
 
 
 # ---------------------------------------------------------------------------

@@ -163,3 +163,40 @@ def test_register_rejects_name_collision():
     jitcache.register("probe_collide", j)  # same object: fine
     with pytest.raises(ValueError, match="probe_collide"):
         jitcache.named_jit(lambda x: x - 2, name="probe_collide")
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compilation_cache_placement(tmp_path, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` places the persistent cache and the
+    helper sets nothing over it; without it the cache is the repo's
+    fixed ``.jax_cache``. Checked in a fresh process, since JAX reads
+    the variable when it is imported."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from delta_crdt_ex_tpu.utils.devices import enable_compilation_cache\n"
+        "path = enable_compilation_cache()\n"
+        "assert jax.config.jax_compilation_cache_dir == path, path\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.jit(lambda x: x * 3 + 1)(jnp.ones(5)).block_until_ready()\n"
+        "print(path)\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True,
+        timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+    path = out.stdout.strip().splitlines()[-1]
+    if env_dir:
+        assert path == str(tmp_path)
+        assert any(name.startswith("jit_") for name in os.listdir(tmp_path))
+    else:
+        assert path == os.path.join(repo, ".jax_cache")
