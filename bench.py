@@ -2180,6 +2180,14 @@ def bench_fleet_mesh():
     )
 
     topo = detected_topology()
+    if topo["platform"] == "tpu":
+        # held off the chip until the four-chip read failure is found
+        raise SystemExit(
+            "--fleet --mesh does not run on a TPU: on a v5e 2x2 the mesh "
+            "fleet read back a wrong map (PERF.md, open questions); run it "
+            "on virtual CPU devices (JAX_PLATFORMS=cpu XLA_FLAGS="
+            "--xla_force_host_platform_device_count=8)"
+        )
     top = mesh_shard_count(topo["global_devices"])
     if top < 2:
         raise SystemExit(f"mesh bench needs at least 2 devices: {topo}")

@@ -251,7 +251,9 @@ def start_fleet(
     between co-mesh members deliver as device-side ``ppermute``
     rotations (only off-mesh destinations take the TCP/frame path).
     Semantics are bit-for-bit the vmap fleet's — state, WAL bytes,
-    acks and wire bytes (``tests/test_mesh_fleet.py``).
+    acks and wire bytes (``tests/test_mesh_fleet.py``) — on virtual CPU
+    devices. On a four-chip TPU v5e that has not held: one run read
+    back a wrong map (``python chip_smoke.py --four-chips``; PERF.md).
 
     ``tree_gossip=True`` members (ISSUE 15) are stamped with ONE
     shared tier-0 cluster key (the mesh plane's, when ``mesh=`` is on)

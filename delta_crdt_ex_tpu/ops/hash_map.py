@@ -44,9 +44,14 @@ wrapper in :mod:`delta_crdt_ex_tpu.models.hash_store`).
 A Pallas TPU kernel serves the probe-window point lookup
 (:func:`probe_lookup_pallas`): the table stays in HBM and each query
 block DMAs exactly its two 128-lane rows into VMEM — the WarpSpeed
-access shape. Selection follows ``ops/pallas_tree.py``: probe once,
-fall back to the pure ``jax.numpy`` path (the CPU/tier-1 reference)
-with the lowering failure surfaced.
+access shape. Selection follows ``ops/pallas_tree.py``: by platform,
+the kernel on a TPU and the pure ``jax.numpy`` path (its reference)
+everywhere else.
+
+No kernel here sorts the whole table on a hot path: a TPU sort of
+``H`` lanes takes minutes to compile at a 1M-key table's width, so
+extraction sorts only the slice's entries and the full read resolves
+winners within probe windows (``winner_all``).
 """
 
 from __future__ import annotations
@@ -513,28 +518,41 @@ def own_delta_counts(
 def _pack_rows(state: HashStore, rows: jnp.ndarray, sel: jnp.ndarray, lanes: int):
     """Pack the selected entries into a dense ``[U, lanes]`` grid, each
     row in arrival (``arr``) order, dead lanes zeroed — the
-    deterministic dense wire form. One sort by ``(row position, arr)``;
-    per-row lane = global rank − row start."""
+    deterministic dense wire form. The selected lanes are first
+    compacted, in table order, into ``U × lanes`` slots (``lanes`` is at
+    least the fullest row's count, so they fit); one stable sort of that
+    buffer by ``(row position, arr)`` then orders them, and per-row lane
+    = rank − row start. Sorting the buffer rather than the whole table
+    keeps the sort's size, and its compile time on a TPU, at the slice's
+    size."""
     L = state.num_buckets
     u = rows.shape[0]
     H = state.table_size
+    n = u * lanes
     _, _, _, row_to_u = _row_lookup(rows, L)
     u_of = row_to_u[_entry_rows(state)]
-    u_clip = jnp.clip(u_of, 0, u - 1)
 
-    sortkey = jnp.where(
-        sel,
-        (u_of.astype(jnp.uint64) << jnp.uint64(32)) | state.arr.astype(jnp.uint64),
-        jnp.uint64(0xFFFFFFFFFFFFFFFF),
+    rank = jnp.cumsum(sel.astype(jnp.int32)) - 1
+    lane_of = (
+        jnp.full(n, H, jnp.int32)
+        .at[jnp.where(sel, rank, n)]
+        .set(jnp.arange(H, dtype=jnp.int32), mode="drop")
     )
-    order = jnp.argsort(sortkey)
-    sel_s = sel[order]
-    u_s = u_clip[order]
+    used = lane_of < H
+    lane_g = jnp.clip(lane_of, 0, H - 1)
+    # unused slots sort last: their row position ``u`` is past every row
+    _, _, order = jax.lax.sort(
+        (jnp.where(used, u_of[lane_g], u), state.arr[lane_g], lane_g),
+        num_keys=2,
+        is_stable=True,
+    )
+    sel_s = jnp.arange(n, dtype=jnp.int32) < jnp.sum(used.astype(jnp.int32))
+    u_s = jnp.clip(u_of[order], 0, u - 1)
     counts = (
         jnp.zeros(u, jnp.int32).at[jnp.where(sel, u_of, u)].add(1, mode="drop")
     )
     starts = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(counts)[:-1]])
-    pos = jnp.arange(H, dtype=jnp.int32) - starts[u_s]
+    pos = jnp.arange(n, dtype=jnp.int32) - starts[u_s]
     tgt_u = jnp.where(sel_s & (pos < lanes), u_s, u)
     tgt_p = jnp.clip(pos, 0, lanes - 1)
 
@@ -648,15 +666,38 @@ def winners_for_keys(state: HashStore, khash: jnp.ndarray):
 
 
 def winner_all(state: HashStore):
-    """Whole-table LWW winners: one lexicographic sort of the flat table
-    (the binned ``winner_all`` shape with a single [1, H] row)."""
+    """Whole-table LWW winners, one ``[1, H]`` row in table order (the
+    binned ``winner_all`` shape; callers select by ``win``). No sort:
+    every dot of a key lies in that key's probe window, so two lanes
+    holding one key are fewer than ``W`` lanes apart. An alive lane wins
+    unless a lane within ``W - 1`` of it holds the same key and ranks
+    above it in (ts, gid, ctr) — ties go to the right-hand lane, so each
+    key keeps exactly one winner. ``2W - 2`` shifted compares replace a
+    whole-table sort, whose TPU compile takes minutes at this width."""
+    W = state.probe_window
+    H = state.table_size
     gid = _table_lookup(
         state.ctx_gid, jnp.clip(state.node, 0, state.replica_capacity - 1)
     )
+    t, g, c = binned_ops._lww_rank(state.ts, gid, state.ctr, state.alive)
+    pad = lambda a: jnp.concatenate([jnp.zeros(W, a.dtype), a, jnp.zeros(W, a.dtype)])
+    cols = tuple(map(pad, (state.key, state.alive, t, g, c)))
+
+    def beaten_at(d, beaten):
+        s = d - (W - 1)  # neighbour offset in [1 - W, W - 1]
+        key, alive, t2, g2, c2 = (
+            jax.lax.dynamic_slice(a, (W + s,), (H,)) for a in cols
+        )
+        above = (t2 > t) | ((t2 == t) & (
+            (g2 > g) | ((g2 == g) & ((c2 > c) | ((c2 == c) & (s > 0))))
+        ))
+        return beaten | ((s != 0) & alive & (key == state.key) & above)
+
+    beaten = jax.lax.fori_loop(0, 2 * W - 1, beaten_at, jnp.zeros(H, bool))
     one = lambda a: a[None, :]
-    return _sorted_winners(
-        one(state.key), one(state.ts), one(gid), one(state.ctr),
-        one(state.alive), one(state.valh),
+    return binned_ops.RowWinners(
+        one(state.alive & ~beaten), one(state.key), one(g), one(state.ctr),
+        one(state.valh), one(t),
     )
 
 
@@ -693,12 +734,17 @@ def rehash(state: HashStore, table_size: int, probe_window: int):
     H_old = state.table_size
     sel = state.alive
     base = probe_base(state.key, table_size)
-    sortkey = jnp.where(
-        sel,
-        (base.astype(jnp.uint64) << jnp.uint64(32)) | state.arr.astype(jnp.uint64),
-        jnp.uint64(0xFFFFFFFFFFFFFFFF),
+    # two 32-bit sort keys, not one packed 64-bit key: the chip emulates
+    # 64-bit compares, and the sort compiles for twice as long
+    _, _, order = jax.lax.sort(
+        (
+            jnp.where(sel, base, jnp.int32(2**31 - 1)),
+            jnp.where(sel, state.arr, jnp.uint32(U32_MAX)),
+            jnp.arange(H_old, dtype=jnp.int32),
+        ),
+        num_keys=2,
+        is_stable=True,
     )
-    order = jnp.argsort(sortkey)
     sel_s = sel[order]
     base_s = base[order].astype(jnp.int64)
     j = jnp.arange(H_old, dtype=jnp.int64)

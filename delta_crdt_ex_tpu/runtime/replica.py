@@ -1619,15 +1619,17 @@ class Replica:
             )
         )
 
-    def canonical_state_bytes(self) -> bytes:
+    def canonical_state(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Topology-independent canonical projection of the CRDT state:
         the sorted per-key LWW winner records plus the causal context
         re-keyed by writer gid (writer-slot assignment order and entry
         lane placement are arrival-order artifacts — two replicas that
         merged the same dot set in different orders agree on THIS
-        projection bit-for-bit). The parity gate hierarchical
-        anti-entropy's tree-vs-flat legs assert in-run (``bench.py
-        --tree``, ``tests/test_tree_sync.py``)."""
+        projection bit-for-bit). Returns ``(winners, gids, ctx)``:
+        ``uint64[n, 5]`` rows of (key hash, writer gid, ctr, value
+        hash, ts) in ascending order, the contributing writers' gids
+        ascending (``uint64[g]``), and their ``uint32[L, g]`` context
+        columns."""
         with self._lock:
             self._flush()
             key, gid, ctr, valh, ts = self._winner_arrays_rows(None)
@@ -1652,11 +1654,14 @@ class Replica:
             # only writers that contributed coverage
             live = np.nonzero((gids != 0) & ctx.any(axis=0))[0]
             g_order = live[np.argsort(gids[live], kind="stable")]
-            return (
-                winners.tobytes()
-                + gids[g_order].tobytes()
-                + ctx[:, g_order].tobytes()
-            )
+            return winners, gids[g_order], ctx[:, g_order]
+
+    def canonical_state_bytes(self) -> bytes:
+        """:meth:`canonical_state` as one byte string — the parity gate
+        hierarchical anti-entropy's tree-vs-flat legs assert in-run
+        (``bench.py --tree``, ``tests/test_tree_sync.py``)."""
+        winners, gids, ctx = self.canonical_state()
+        return winners.tobytes() + gids.tobytes() + ctx.tobytes()
 
     def _note_state_changed(
         self, count_fn: Callable[[], int], keep_read_cache: bool = False
