@@ -23,6 +23,7 @@ import numpy as np
 from delta_crdt_ex_tpu.models.binned import BinnedStore, pow2_tier as _pow2, pow4_tier as _pow4
 from delta_crdt_ex_tpu.ops import binned as binned_ops
 from delta_crdt_ex_tpu.ops.apply import OP_ADD, OP_CLEAR, OP_PAD, OP_REMOVE
+from delta_crdt_ex_tpu.utils import transfers
 from delta_crdt_ex_tpu.utils.jitcache import named_jit
 
 # named_jit = jax.jit + compile-cache audit registration under the
@@ -40,6 +41,11 @@ jit_winner_rows = named_jit(binned_ops.winner_rows)
 jit_winner_all = named_jit(binned_ops.winner_all)
 jit_compact_rows = named_jit(binned_ops.compact_rows)
 jit_tree_from_leaves = named_jit(binned_ops.tree_from_leaves)
+
+#: the merge kernel's escape flags, read once per kernel call: the
+#: blocking wait on every merge, so it is a counted crossing and a
+#: ``crdt.readback.binned_map.merge_flags`` span
+_TR_MERGE_FLAGS = transfers.register("binned_map.merge_flags")
 
 
 class GroupedBatch:
@@ -191,19 +197,22 @@ def merge_rows_into(state: BinnedStore, sl, on_grow=None):
     :class:`CtxGapError` on a non-contiguous delta-interval."""
     while True:
         res = jit_merge_rows(state, sl)
-        if bool(res.ok):
+        ok, ctx_gap, gid_grow, fill_grow = _TR_MERGE_FLAGS.get(
+            (res.ok, res.need_ctx_gap, res.need_gid_grow, res.need_fill_grow)
+        )
+        if ok:
             return res.state, res
-        if bool(res.need_ctx_gap):
+        if ctx_gap:
             err = CtxGapError(_CTX_GAP_MSG)
             # per-row gap mask (host copy: error path, cost irrelevant)
             # so grouped callers can isolate the gapped member slices
             err.gap_rows = np.asarray(res.gap_row)
             raise err
-        if bool(res.need_gid_grow):
+        if gid_grow:
             state = state.grow(replica_capacity=state.replica_capacity * 2)
             if on_grow:
                 on_grow(state)
-        if bool(res.need_fill_grow):
+        if fill_grow:
             state = state.grow(bin_capacity=state.bin_capacity * 2)
             if on_grow:
                 on_grow(state)
@@ -426,7 +435,8 @@ def merge_group_into(state: BinnedStore, arrays_list: list, on_grow=None):
     through ``offsets``) so the caller replays only those solo and keeps
     the clean members in one grouped dispatch.
     """
-    sl, offsets = combine_entry_arrays(arrays_list)
+    with jax.profiler.TraceAnnotation("crdt.combine"):
+        sl, offsets = combine_entry_arrays(arrays_list)
     try:
         new_state, res = merge_rows_into(state, sl, on_grow=on_grow)
     except CtxGapError as err:

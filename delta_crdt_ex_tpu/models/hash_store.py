@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from delta_crdt_ex_tpu.models.binned import pow2_tier as _pow2
+from delta_crdt_ex_tpu.utils import transfers
 
 #: lanes per probe group (window bases are group-aligned)
 GROUP = 8
@@ -222,6 +223,10 @@ class _Jit:
 
 jit = _Jit()
 
+#: the merge kernel's escape flags and window fill, read once per
+#: kernel call (the merge's blocking wait, counted and spanned)
+_TR_MERGE_FLAGS = transfers.register("hash_store.merge_flags")
+
 
 def grow_table(state: HashStore, on_grow=None) -> HashStore:
     """THE growth event: rehash ×2 (at least — a nearly-empty table
@@ -269,18 +274,21 @@ def merge_rows_into(state: HashStore, sl, on_grow=None):
 
     while True:
         res = jit.merge_rows(state, sl)
-        ok, wfill = jax.device_get((res.ok, res.max_window_fill))
-        if bool(ok):
+        ok, wfill, ctx_gap, gid_grow, fill_grow = _TR_MERGE_FLAGS.get(
+            (res.ok, res.max_window_fill, res.need_ctx_gap,
+             res.need_gid_grow, res.need_fill_grow)
+        )
+        if ok:
             return maybe_rehash(res.state, int(wfill), on_grow=on_grow), res
-        if bool(np.asarray(res.need_ctx_gap)):
+        if ctx_gap:
             err = CtxGapError(_CTX_GAP_MSG)
             err.gap_rows = np.asarray(res.gap_row)
             raise err
-        if bool(np.asarray(res.need_gid_grow)):
+        if gid_grow:
             state = state.grow(replica_capacity=state.replica_capacity * 2)
             if on_grow:
                 on_grow(state)
-        if bool(np.asarray(res.need_fill_grow)):
+        if fill_grow:
             state = grow_table(state, on_grow=on_grow)
 
 
@@ -290,7 +298,8 @@ def merge_group_into(state: HashStore, arrays_list: list, on_grow=None):
     dispatch, ``gapped_members`` mapped through member offsets)."""
     from delta_crdt_ex_tpu.models.binned_map import CtxGapError, combine_entry_arrays
 
-    sl, offsets = combine_entry_arrays(arrays_list)
+    with jax.profiler.TraceAnnotation("crdt.combine"):
+        sl, offsets = combine_entry_arrays(arrays_list)
     try:
         new_state, res = merge_rows_into(state, sl, on_grow=on_grow)
     except CtxGapError as err:

@@ -27,6 +27,7 @@ data-dependent control flow, and it lives on the host.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import os
@@ -1813,16 +1814,18 @@ class Replica:
         first push any own fresh deltas directly (delta mode — the walk
         then usually finds the trees already equal), then open the
         digest-walk round (the repair + transitive-relay path)."""
-        with self._lock:
-            self._flush()
-            if self.tree_gossip:
-                self._tree_probe_down()
-            self._monitor_neighbours()
-            self._push_deltas()
-            self._open_walks()
-        # the tick's relay epoch: everything merged since the last flush
-        # re-emits as ONE merged slice per tree link (no-op when flat)
-        self._relay_flush()
+        with tracing.annotate("crdt.sync_tick"):
+            with self._lock:
+                self._flush()
+                if self.tree_gossip:
+                    self._tree_probe_down()
+                self._monitor_neighbours()
+                self._push_deltas()
+                self._open_walks()
+            # the tick's relay epoch: everything merged since the last
+            # flush re-emits as ONE merged slice per tree link (no-op
+            # when flat)
+            self._relay_flush()
 
     def _open_walks(self, send=None) -> None:
         """Open digest-walk rounds toward every monitored neighbour —
@@ -1970,15 +1973,16 @@ class Replica:
         """Solo (per-replica) extraction of one planned push job — the
         fleet substitutes the matching lane of one vmapped extraction,
         bit-for-bit the same slice."""
-        if job.kind == "delta":
-            return self.model.extract_own_delta(
-                self.state,
-                jnp.asarray(job.rows),
-                jnp.int32(self.self_slot),
-                jnp.uint64(self.node_id),
-                jnp.asarray(job.lo),
-            )
-        return self.model.extract_rows(self.state, jnp.asarray(job.rows))
+        with tracing.annotate("crdt.slice_extract"):
+            if job.kind == "delta":
+                return self.model.extract_own_delta(
+                    self.state,
+                    jnp.asarray(job.rows),
+                    jnp.int32(self.self_slot),
+                    jnp.uint64(self.node_id),
+                    jnp.asarray(job.lo),
+                )
+            return self.model.extract_rows(self.state, jnp.asarray(job.rows))
 
     def _emit_push_job(self, job: "_PushJob", sl, send=None) -> None:
         """Fan one extracted push slice out to the job's peers and
@@ -2423,6 +2427,10 @@ class Replica:
         forward_fleet_entries(self.transport, msg.entries, local)
 
     def _handle_diff(self, msg: sync_proto.DiffMsg) -> None:
+        with tracing.annotate("crdt.walk"):
+            self._handle_diff_inner(msg)
+
+    def _handle_diff_inner(self, msg: sync_proto.DiffMsg) -> None:
         if (
             self.tree_gossip
             and msg.frm != self.addr
@@ -2596,8 +2604,9 @@ class Replica:
         """Single-plane wire form of a RowSlice: the column arrays
         (context rows for exactly the shipped buckets — bucket-atomic
         sync: coverage never outruns content) plus the payload dict."""
-        host, payloads = self._slice_payload_host(sl, rows)
-        return self._slice_arrays(sl, host, target_device, rows), payloads
+        with tracing.annotate("crdt.slice_wire"):
+            host, payloads = self._slice_payload_host(sl, rows)
+            return self._slice_arrays(sl, host, target_device, rows), payloads
 
     def _slice_bodies(self, sl, rows: np.ndarray, peers) -> tuple[dict, dict]:
         """Fan-out wire bodies: ONE arrays dict per distinct pinned
@@ -2606,18 +2615,19 @@ class Replica:
         a 64-neighbour fan-out across 8 devices builds 8 bodies, not 64
         and not a host fallback for everyone (VERDICT r3 weak #4).
         Returns ``({peer: arrays}, payloads)``."""
-        host, payloads = self._slice_payload_host(sl, rows)
-        device_of = getattr(self.transport, "device_of", None)
-        groups: dict[Any, list] = {}
-        for n in peers:
-            d = device_of(n) if device_of is not None else None
-            groups.setdefault(d, []).append(n)
-        by_peer: dict[Any, dict] = {}
-        for dev, members in groups.items():
-            arrays = self._slice_arrays(sl, host, dev, rows)
-            for n in members:
-                by_peer[n] = arrays
-        return by_peer, payloads
+        with tracing.annotate("crdt.slice_wire"):
+            host, payloads = self._slice_payload_host(sl, rows)
+            device_of = getattr(self.transport, "device_of", None)
+            groups: dict[Any, list] = {}
+            for n in peers:
+                d = device_of(n) if device_of is not None else None
+                groups.setdefault(d, []).append(n)
+            by_peer: dict[Any, dict] = {}
+            for dev, members in groups.items():
+                arrays = self._slice_arrays(sl, host, dev, rows)
+                for n in members:
+                    by_peer[n] = arrays
+            return by_peer, payloads
 
     def _extract_rows_wire(self, buckets: np.ndarray, device) -> tuple[dict, dict]:
         """Extract the given bucket rows as one wire-tier-padded entries
@@ -2626,7 +2636,8 @@ class Replica:
         padding convention cannot drift between them."""
         rows = np.full(_wire(max(len(buckets), 1)), -1, np.int32)
         rows[: len(buckets)] = np.asarray(buckets, np.int32)
-        sl = self.model.extract_rows(self.state, jnp.asarray(rows))
+        with tracing.annotate("crdt.slice_extract"):
+            sl = self.model.extract_rows(self.state, jnp.asarray(rows))
         return self._slice_wire(sl, rows, device)
 
     def _device_of(self, peer):
@@ -2680,7 +2691,8 @@ class Replica:
         want_diffs = self.on_diffs is not None
         keys_b = self._winner_records_rows(rows_np[rows_np >= 0]) if want_diffs else {}
         # payloads first: diff values for incoming winners must resolve
-        self._register_slice_payloads(msg.payloads)
+        with tracing.annotate("crdt.payloads"):
+            self._register_slice_payloads(msg.payloads)
 
         try:
             res = self._merge_with_growth(sl)
@@ -3288,8 +3300,9 @@ class Replica:
         # payloads first, whole group: the merged winners' values must
         # resolve; idempotent, so the gap fallback below re-registers
         # harmlessly
-        for m in msgs:
-            self._register_slice_payloads(m.payloads)
+        with tracing.annotate("crdt.payloads"):
+            for m in msgs:
+                self._register_slice_payloads(m.payloads)
         try:
             with tracing.annotate("crdt.merge_group"):
                 self.state, res, offsets = self.model.merge_group_into(
@@ -3378,6 +3391,10 @@ class Replica:
         readbacks when one is active). Caller holds the lock, has
         stored the merged state, and has invalidated the tree/read
         caches."""
+        with tracing.annotate("crdt.commit"):
+            self._commit_entries_group_inner(msgs, offsets, counts_fn, dt)
+
+    def _commit_entries_group_inner(self, msgs: list, offsets, counts_fn, dt: float) -> None:
         # durability happens-before publication (crdtlint FAULT003): the
         # whole group's WAL records land before the serving plane or
         # telemetry can observe the merge. A failed append rolls the seq
@@ -3652,7 +3669,7 @@ class Replica:
         ``_maybe_gc`` — so a long-running replica with remove churn keeps
         ``_payloads``/``_key_terms`` proportional to live entries
         (VERDICT r2 weak #3) at amortized O(1) per op."""
-        with self._lock:
+        with tracing.annotate("crdt.gc"), self._lock:
             # store-layout-agnostic (ISSUE 8): a dot's bucket is a pure
             # function of its key, so derive it instead of reading the
             # binned row index — the same pass serves the [L, B] rows
@@ -3735,33 +3752,38 @@ class Replica:
             top = self._telemetry_defer is None
             if top:
                 self._telemetry_defer = []
-        try:
-            for _ in range(8):
-                if drain is not None:
-                    batch = drain(self.addr, self.ingress_batch)
-                else:  # transports predating the batch-receive API
-                    batch = self.transport.drain(self.addr)
-                if not batch:
-                    break
-                n += len(batch)
-                self._handle_batch(batch)
-                if drain is None or len(batch) < self.ingress_batch:
-                    break
-            # end-of-drain relay epoch (ISSUE 15): everything this pass
-            # merged re-emits as ONE coalesced slice per tree link, so
-            # propagation cascades hop-by-hop through relays instead of
-            # waiting a sync interval per tree level
-            self._relay_flush()
-        finally:
-            if top:
-                with self._lock:
-                    deferred, self._telemetry_defer = self._telemetry_defer, None
-                if deferred:
-                    # ONE transfer for every parked accounting pytree
-                    # (device_get passes already-host values through)
-                    fetched = _TR_DRAIN_ACCOUNTING.get([f() for f, _e in deferred])
-                    for (_f, emit), data in zip(deferred, fetched):
-                        emit(data)
+        # the crdt.drain span opens at the first non-empty batch, so an
+        # idle loop iteration emits none
+        with contextlib.ExitStack() as span:
+            try:
+                for _ in range(8):
+                    if drain is not None:
+                        batch = drain(self.addr, self.ingress_batch)
+                    else:  # transports predating the batch-receive API
+                        batch = self.transport.drain(self.addr)
+                    if not batch:
+                        break
+                    if not n:
+                        span.enter_context(tracing.annotate("crdt.drain"))
+                    n += len(batch)
+                    self._handle_batch(batch)
+                    if drain is None or len(batch) < self.ingress_batch:
+                        break
+                # end-of-drain relay epoch: everything this pass merged
+                # re-emits as ONE coalesced slice per tree link, so
+                # propagation cascades hop-by-hop through relays instead
+                # of waiting a sync interval per tree level
+                self._relay_flush()
+            finally:
+                if top:
+                    with self._lock:
+                        deferred, self._telemetry_defer = self._telemetry_defer, None
+                    if deferred:
+                        # ONE transfer for every parked accounting pytree
+                        # (device_get passes already-host values through)
+                        fetched = _TR_DRAIN_ACCOUNTING.get([f() for f, _e in deferred])
+                        for (_f, emit), data in zip(deferred, fetched):
+                            emit(data)
         if obs is not None and n:
             # drain-lag accounting: one registry update per drain PASS
             # (never per message — the hot path stays amortised)

@@ -1,55 +1,20 @@
-"""Tracing / profiling (SURVEY §5.1).
+"""Named host spans (SURVEY §5.1).
 
-The reference's profiling story is dev-time ``:fprof`` wrapped in
-``bench/basic_operations.exs:9-23`` (trace 1000 mutations, analyse to a
-file). The TPU-native equivalents:
-
-- :func:`trace` — context manager around any region, capturing a
-  ``jax.profiler`` device trace (view with TensorBoard / xprof);
-- :func:`annotate` — cheap named spans (``jax.profiler.TraceAnnotation``)
-  used by the replica around its merge/flush hot paths;
-- :func:`profile_mutations` — the fprof-analog: run N mutations against a
-  replica under a device trace plus host-side phase timers, and return
-  the wall-time breakdown.
+:func:`annotate` opens a ``jax.profiler.TraceAnnotation``: a span on
+the profiler's host plane, on the same clock as the device's
+operations, and a no-op cost when no trace is being captured. The
+replica names its spans ``crdt.<step>`` (its loop's ``crdt.sync_tick``
+and ``crdt.drain``, the anti-entropy steps, the merges), and every
+audited readback of the transfer ledger opens ``crdt.readback.<site>``
+(``utils/transfers.py``), so a trace puts each stretch in which the
+device waits down to the host step that held it.
 """
 
 from __future__ import annotations
 
-import contextlib
-import time
-from typing import Any
-
 import jax
-
-
-@contextlib.contextmanager
-def trace(logdir: str):
-    """Capture a jax.profiler device trace for the enclosed region."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 def annotate(name: str):
     """Named span visible in device traces (no-op cost when not tracing)."""
     return jax.profiler.TraceAnnotation(name)
-
-
-def profile_mutations(crdt, n: int = 1000, logdir: str | None = None) -> dict[str, Any]:
-    """Profile ``n`` add mutations (reference ``bench/basic_operations.exs:
-    9-23``): optional device trace + host wall-time split."""
-    ctx = trace(logdir) if logdir else contextlib.nullcontext()
-    t0 = time.perf_counter()
-    with ctx:
-        for x in range(n):
-            crdt.mutate("add", [f"key{x}", "value"])
-        crdt.hibernate()
-    total = time.perf_counter() - t0
-    return {
-        "mutations": n,
-        "total_s": total,
-        "per_op_us": total / n * 1e6,
-        "trace_dir": logdir,
-    }

@@ -11,7 +11,9 @@ hot-path device↔host crossing goes through an **audited site**
 and bytes per site label. crdtlint TRANSFER001 makes any *raw* crossing
 in a hot module red; TRANSFER002 cross-checks the declared site labels
 against use (ghost labels, collisions) the way OBS001 cross-checks
-declared telemetry events.
+declared telemetry events. Every audited ``get`` also opens a
+``crdt.readback.<label>`` profiler span, so a trace shows how long the
+host waited on each site as well as how often it crossed.
 
 Zero overhead when observability is disabled: the hot path pays two
 integer adds under an uncontended lock (negligible next to the transfer
@@ -53,13 +55,17 @@ class TransferSite:
     constants by convention — crdtlint TRANSFER001 recognises
     ``<handle>.get/.put`` as the audited crossing form."""
 
-    __slots__ = ("label", "origin", "count", "bytes")
+    __slots__ = ("label", "origin", "count", "bytes", "span")
 
     def __init__(self, label: str, origin: tuple) -> None:
         self.label = label
         self.origin = origin
         self.count = 0
         self.bytes = 0
+        # the profiler span every get opens, so a trace shows the time
+        # the host waited on this site (TraceAnnotation directly: the
+        # runtime's tracing helper sits above utils in the import graph)
+        self.span = "crdt.readback." + label
 
     def note(self, n_bytes: int, crossings: int = 1) -> None:
         """Manual accounting for a crossing the wrappers cannot wrap
@@ -70,9 +76,11 @@ class TransferSite:
 
     def get(self, value):
         """Audited ``jax.device_get``: one counted crossing for the
-        whole pytree (host leaves pass through, like ``device_get``)."""
+        whole pytree (host leaves pass through, like ``device_get``),
+        inside a ``crdt.readback.<label>`` profiler span."""
         self.note(_nbytes(value))
-        return jax.device_get(value)
+        with jax.profiler.TraceAnnotation(self.span):
+            return jax.device_get(value)
 
     def put(self, value, device=None):
         """Audited ``jax.device_put`` (``device`` may be a Device or a

@@ -1,24 +1,16 @@
-"""Auxiliary subsystems: tracing, interval checkpointing, telemetry, gc."""
+"""Auxiliary subsystems: interval checkpointing, telemetry, gc."""
 
 import time
 
 from delta_crdt_ex_tpu import AWLWWMap, MemoryStorage
 from delta_crdt_ex_tpu.api import start_link
 from delta_crdt_ex_tpu.runtime import telemetry
-from delta_crdt_ex_tpu.runtime.tracing import profile_mutations
 
 
 def mk(transport, clock, **opts):
     opts.setdefault("capacity", 64)
     opts.setdefault("tree_depth", 6)
     return start_link(AWLWWMap, threaded=False, transport=transport, clock=clock, **opts)
-
-
-def test_profile_mutations(transport, shared_clock):
-    c = mk(transport, shared_clock)
-    out = profile_mutations(c, n=20)
-    assert out["mutations"] == 20 and out["total_s"] > 0
-    assert len(c.read()) == 20
 
 
 def test_telemetry_sync_done_counts(transport, shared_clock):
